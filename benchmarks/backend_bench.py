@@ -35,6 +35,7 @@ import time
 import numpy as np
 
 from repro.core import iterated_greedy, large_scale_scenario, plan_from_assignment
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sim import simulate_plan
 from repro.stream.backend import decode_batch, has_jax
 
@@ -241,6 +242,7 @@ def main(argv=None):
     p.add_argument("--json", default=None,
                    help="output path (default BENCH_backend.json)")
     args = p.parse_args(argv)
+    enable_compile_cache()
     record = {
         "bench": "backend_throughput",
         "montecarlo": run_montecarlo(args.trials),

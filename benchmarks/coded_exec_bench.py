@@ -21,6 +21,7 @@ import numpy as np
 
 from repro.core import (Scenario, iterated_greedy, plan_from_assignment,
                         small_scale_scenario)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime import CodedExecutor
 from repro.runtime.coded_grads import coded_grad_aggregate, encode_grad_shards
 from repro.stream import (BackendConfig, StreamConfig, StreamingExecutor,
@@ -192,6 +193,7 @@ def main(argv=None):
                    choices=("numpy", "jax", "pallas"),
                    help="streaming verification backend")
     args = p.parse_args(argv)
+    enable_compile_cache()
     run_executor()
     run_kernels()
     run_coded_grads()

@@ -18,6 +18,8 @@ from __future__ import annotations
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     from . import (ablation_redundancy, backend_bench, coded_exec_bench,
                    fig2_3_markov, fig4_delay, fig5_cdf, fig6_commrate,

@@ -50,6 +50,7 @@ import argparse
 import json
 import os
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve_coded import (CODING_SCOPES, EXECUTION_MODES,
                                CodedServingBridge, serve_policy_sweep,
                                synthetic_requests)
@@ -356,6 +357,7 @@ def main(argv=None):
                         "parse_fault_spec syntax; 'none' = zero rates "
                         "with detection armed)")
     args = p.parse_args(argv)
+    enable_compile_cache()
     run_serve_bench(requests=args.requests, gen_len=args.gen_len,
                     masters=args.masters, slots=args.slots, rate=args.rate,
                     backend=args.backend,

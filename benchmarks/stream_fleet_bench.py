@@ -33,6 +33,7 @@ import time
 import numpy as np
 
 from repro.core.problem import Scenario
+from repro.launch.compile_cache import enable_compile_cache
 from repro.stream import (BackendConfig, ReplanPolicy, StreamConfig,
                           StreamingExecutor, WorkerEvent, poisson_sources)
 
@@ -206,6 +207,7 @@ def main(argv=None):
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--json", dest="json_path", default=None)
     args = p.parse_args(argv)
+    enable_compile_cache()
     run_bench(tasks=args.tasks, masters=args.masters, workers=args.workers,
               utilization=args.utilization, churn_period=args.churn_period,
               event_batch=args.event_batch, subset_tasks=args.subset_tasks,

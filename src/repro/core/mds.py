@@ -123,8 +123,14 @@ def parity_counters(row_ids, draws) -> np.ndarray:
 
 
 def _uniform24(bits):
-    """uint32 → float32 uniform in [0, 1) from the top 24 bits (exact)."""
-    return (bits >> np.uint32(8)).astype("float32") * np.float32(2.0 ** -24)
+    """uint32 → float32 uniform in [0, 1) from the top 24 bits (exact).
+
+    The shifted value is below 2^24, so it converts through int32 without
+    loss; Mosaic (the TPU kernel compiler) has no direct uint32 → float32
+    cast, and int32 → float32 is the one every backend accepts.
+    """
+    top = (bits >> np.uint32(8)).astype("int32")
+    return top.astype("float32") * np.float32(2.0 ** -24)
 
 
 def counter_gaussian_tile(k0, k1, ctrs, cols, scale):

@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .matmul import F32_PRECISION
+
 __all__ = ["coded_matvec_pallas"]
 
 
@@ -27,7 +29,8 @@ def _matvec_kernel(a_ref, x_ref, o_ref, acc_ref, *, k_steps: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(a_ref[...], x_ref[...],
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=F32_PRECISION)
 
     @pl.when(pl.program_id(1) == k_steps - 1)
     def _flush():

@@ -20,6 +20,13 @@ __all__ = ["matmul_pallas", "DEFAULT_BLOCK"]
 
 DEFAULT_BLOCK = (128, 128, 128)  # (bm, bn, bk)
 
+#: float32 operands contract at full float32 precision on the MXU (Mosaic
+#: and XLA may otherwise take fewer bf16 passes): coded products feed an
+#: MDS decode that amplifies product error by the parity block's condition
+#: number.  Argued, not measured: no chip run has yet compared it with the
+#: default precision against the decode tolerance, or timed its cost.
+F32_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int):
     @pl.when(pl.program_id(2) == 0)
@@ -27,7 +34,8 @@ def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(a_ref[...], b_ref[...],
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=F32_PRECISION)
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _flush():

@@ -29,7 +29,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core import mds
-from .matmul import DEFAULT_BLOCK, matmul_pallas
+from .matmul import DEFAULT_BLOCK, F32_PRECISION, matmul_pallas
 
 __all__ = ["mds_encode_pallas", "counter_parity_rows_pallas",
            "gen_parity_matvec_pallas"]
@@ -102,9 +102,11 @@ def _gen_matvec_kernel(key_ref, scale_ref, ctr_ref, w_ref, x_ref, o_ref,
     # contract the generated tile against the resident W tile: the encoded
     # parity row (R @ W) is never formed — only its product with x
     wx = jnp.dot(w_ref[...], x_ref[...],
-                 preferred_element_type=jnp.float32)          # (bk, C)
+                 preferred_element_type=jnp.float32,
+                 precision=F32_PRECISION)                 # (bk, C)
     acc_ref[...] += jnp.dot(r_blk, wx,
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=F32_PRECISION)
 
     @pl.when(pl.program_id(1) == k_steps - 1)
     def _flush():

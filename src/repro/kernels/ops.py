@@ -18,7 +18,7 @@ from . import ref
 from ..core import mds
 from ..obs import device_span
 from .coded_matvec import coded_matvec_pallas
-from .matmul import matmul_pallas
+from .matmul import F32_PRECISION, matmul_pallas
 from .mds_encode import (counter_parity_rows_pallas, gen_parity_matvec_pallas,
                          mds_encode_pallas)
 from .wkv6 import wkv6_pallas
@@ -147,9 +147,13 @@ def _derive_rows_xla(L: int):
     return jax.jit(f)
 
 
+def _mm(a, b):
+    return jnp.matmul(a, b, precision=F32_PRECISION)
+
+
 @functools.lru_cache(maxsize=None)
 def _gen_contract():
-    return jax.jit(lambda r, w, x: r @ (w @ x))
+    return jax.jit(lambda r, w, x: _mm(r, _mm(w, x)))
 
 
 #: steady-state serving replays one frozen counter schedule per plan
@@ -183,10 +187,11 @@ def _gen_vmap_step(n_specs: int):
     the virtual path costs one dispatch like the materialised one."""
     def f(tiles, x, lanes, rs, ws):
         T, R, _ = tiles.shape
-        flat = jax.vmap(lambda t: t @ x)(tiles).reshape(T * R, -1)
+        flat = jax.vmap(lambda t: _mm(t, x))(tiles).reshape(T * R, -1)
         for i in range(n_specs):
+            # x may carry zero rows padding D up to the tile width
             flat = flat.at[lanes[i]].set(
-                (rs[i] @ (ws[i] @ x)).astype(flat.dtype))
+                _mm(rs[i], _mm(ws[i], x[:ws[i].shape[1]])).astype(flat.dtype))
         return flat.reshape(T, R, -1)
     return jax.jit(f)
 
@@ -198,7 +203,8 @@ def gen_parity_products(key: Tuple[int, int], ctrs, w: jnp.ndarray,
     """Generated-parity shard products (n, C): ``R_gen[ctrs] @ (W @ x)``.
 
     ``w`` (L, D) float32 systematic weights (device-resident), ``x``
-    (D, C).  The fused kernel derives each parity tile from the packed
+    (D', C) with D' ≥ D (rows past D are the zero padding of the packed
+    tiles' contraction width).  The fused kernel derives each parity tile from the packed
     row counters and contracts it against W tile-by-tile — the virtual
     parity path's device execution, with no ``WR`` mirror in HBM.
     """
@@ -212,7 +218,7 @@ def gen_parity_products(key: Tuple[int, int], ctrs, w: jnp.ndarray,
                      args={"rows": int(n), "L": int(L)}) as fence:
         if interpret:
             r = _gen_rows_device(key, ctrs_host, L)
-            out = fence(_gen_contract()(r, w, x))
+            out = fence(_gen_contract()(r, w, x[:D]))
         else:
             ctrs_p = _pad_to(ctrs, 0, block_rows)
             wp = _pad_to(_pad_to(w, 0, block_k), 1, 128)
@@ -298,7 +304,7 @@ def coded_shard_matmul_batch(tiles: jnp.ndarray, x: jnp.ndarray, *,
             return fence(_gen_vmap_step(len(specs))(tiles, x, lanes,
                                                     rs, ws))
         if mode == "vmap":
-            out = fence(jax.vmap(lambda t: t @ x)(tiles))
+            out = fence(jax.vmap(lambda t: _mm(t, x))(tiles))
         else:
             flat = coded_matvec_pallas(tiles.reshape(T * R, K), x,
                                        block_rows=block_rows,

@@ -4,8 +4,10 @@
         --requests 16 --prompt-len 32 --gen-len 24
 
 Runs a small request pool through prefill → token-by-token decode with a
-shared jitted decode step and per-request completion, reporting throughput
-and verifying the decode path against the full forward pass.
+shared jitted decode step, reporting throughput and verifying every step's
+logits against a teacher-forced full forward pass (``DECODE_TOL``; a
+mismatch exits 1).  ``--no-smoke`` serves the arch at its published
+widths.
 
 With ``--coded`` the same model is served through the coded-computation
 bridge (:mod:`repro.serve_coded`): per ``--coding-scope`` the output-head
@@ -32,14 +34,18 @@ import time
 import numpy as np
 
 __all__ = ["build_model", "serving_fns", "zero_caches", "head_matrix",
-           "main"]
+           "build_parser", "decode_vs_forward_err", "DECODE_TOL", "main"]
 
 
 _MODEL_CACHE: dict = {}
 
 
-def build_model(arch: str, *, smoke: bool = True, seed: int = 0):
+def build_model(arch, *, smoke: bool = True, seed: int = 0):
     """Config + initialised parameters for ``arch`` (smoke-sized or full).
+
+    ``arch`` is a registry name, or an :class:`~repro.models.ArchConfig`
+    served as given (``smoke`` is then ignored) — how a run cuts a
+    published config's depth or vocabulary to fit a budget.
 
     Memoised per (arch, smoke, seed): init is deterministic and params are
     treated as read-only everywhere, so repeated bridge/test construction
@@ -49,7 +55,10 @@ def build_model(arch: str, *, smoke: bool = True, seed: int = 0):
         import jax
         from repro.configs import get_config, get_smoke_config
         from repro.models import init_model
-        cfg = get_smoke_config(arch) if smoke else get_config(arch)
+        if not isinstance(arch, str):
+            cfg = arch
+        else:
+            cfg = get_smoke_config(arch) if smoke else get_config(arch)
         params = init_model(jax.random.PRNGKey(seed), cfg)
         _MODEL_CACHE[key] = (cfg, params)
     return _MODEL_CACHE[key]
@@ -99,10 +108,25 @@ def head_matrix(cfg, params) -> np.ndarray:
     return W.astype(np.float64)
 
 
-def main(argv=None) -> int:
+#: decode-vs-full-forward tolerance on ``max|Δlogit| / (1 + max|logit|)``
+#: per parameter dtype.  The cached decode and the full forward run the
+#: same weights through different contraction shapes (one query against
+#: the KV cache vs the whole causal sequence), so they round differently:
+#: float32 agrees to ~1e-6 (1e-4 leaves room for 16 layers of it); bf16
+#: keeps 8 mantissa bits (ε = 2^-8 ≈ 3.9e-3) in the residual stream of
+#: every layer, so its logits drift by a few ε — 5e-2 is about a dozen ε.
+#: A decode position off by one either way (so a wrong cache slot) reads
+#: 0.13-0.28 on the smoke config in both dtypes (tests/test_launch_serve.py).
+DECODE_TOL = {"float32": 1e-4, "bfloat16": 5e-2}
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the arch's reduced smoke config "
+                         "(--no-smoke: its published widths)")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen-len", type=int, default=24)
@@ -118,6 +142,19 @@ def main(argv=None) -> int:
                     help="which matmuls run coded: the output head only, "
                          "+FFN up/down, or the full trunk incl. attention "
                          "q/k/v/o (--coded serving)")
+    ap.add_argument("--backend", default="numpy",
+                    choices=("numpy", "jax", "pallas"),
+                    help="coded encode / product / decode backend "
+                         "(--coded serving)")
+    ap.add_argument("--device-products", action=argparse.BooleanOptionalAction,
+                    default=False,
+                    help="run the packed shard products on the device "
+                         "kernels in float32 (jax/pallas backends, "
+                         "--coded serving)")
+    ap.add_argument("--parity-storage", default="materialized",
+                    choices=("materialized", "virtual"),
+                    help="cache encoded parity rows, or derive them from "
+                         "threefry counters on demand (--coded serving)")
     ap.add_argument("--steps-per-dispatch", type=int, default=1,
                     help="decode tokens generated per coded admission "
                          "(--coded serving)")
@@ -142,7 +179,13 @@ def main(argv=None) -> int:
                     help="route every coded decode through the "
                          "stacked-LS tail (bit-identical at exactly L "
                          "rows) (--coded serving)")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None) -> int:
+    args = build_parser().parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.coded:
         from repro.serve_coded import run_coded_smoke
@@ -152,6 +195,9 @@ def main(argv=None) -> int:
                                prompt_len=args.prompt_len,
                                gen_len=args.gen_len, seed=args.seed,
                                coding_scope=args.coding_scope,
+                               backend=args.backend,
+                               device_products=args.device_products,
+                               parity_storage=args.parity_storage,
                                steps_per_dispatch=args.steps_per_dispatch,
                                execution=args.execution,
                                trace=args.trace, faults=args.faults,
@@ -175,6 +221,7 @@ def main(argv=None) -> int:
 
     t0 = time.time()
     logits, caches = prefill_fn(params, batch, caches)
+    step_logits = [logits[:, -1]]
     tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
     t_prefill = time.time() - t0
 
@@ -183,6 +230,7 @@ def main(argv=None) -> int:
     for i in range(G - 1):
         pos = jnp.full((B,), P + i, jnp.int32)
         logits, caches = decode_fn(params, tok, pos, caches)
+        step_logits.append(logits[:, -1])
         tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
         out_tokens.append(tok)
     jax.block_until_ready(tok)
@@ -192,9 +240,39 @@ def main(argv=None) -> int:
     print(f"[serve] {B} requests, prompt {P}, generated {gen.shape[1]} toks")
     print(f"[serve] prefill {t_prefill*1e3:.0f}ms  decode "
           f"{t_decode*1e3:.0f}ms  ({B*(G-1)/max(t_decode,1e-9):.0f} tok/s)")
-    assert not np.any(np.isnan(gen)), "NaN tokens"
     print(f"[serve] sample continuation: {gen[0][:12].tolist()}")
-    return 0
+    if cfg.enc_dec:
+        return 0
+    # teacher-forced check: the full forward over prompt + generated
+    # tokens must give the logits each cached step produced, position by
+    # position (logits, not argmax tokens — random weights make near-ties)
+    err = decode_vs_forward_err(cfg, params, prompts, gen, step_logits)
+    tol = DECODE_TOL[cfg.dtype]
+    ok = bool(err <= tol)
+    print(f"[serve] decode vs full forward: max_rel_err={err:.3e} "
+          f"tol={tol:.1e} {'ok' if ok else 'FAILED'}")
+    return 0 if ok else 1
+
+
+def decode_vs_forward_err(cfg, params, prompts, gen, step_logits) -> float:
+    """``max|Δlogit| / (1 + max|logit|)`` between the served per-step
+    logits and a teacher-forced full :func:`repro.models.model_fwd`.
+
+    ``step_logits[i]`` (B, V) is what the server emitted for position
+    ``P - 1 + i`` (the prefill's last position, then each decode step);
+    the full forward runs over the prompt plus every generated token that
+    was fed back."""
+    import jax
+    import jax.numpy as jnp
+    from repro.models import model_fwd
+    P = prompts.shape[1]
+    G = len(step_logits)
+    seq = jnp.concatenate([prompts, jnp.asarray(gen[:, :G - 1], jnp.int32)],
+                          axis=1)
+    fwd = jax.jit(lambda p, t: model_fwd(p, {"tokens": t}, cfg=cfg)["logits"])
+    ref = np.asarray(fwd(params, seq)[:, P - 1:], np.float32)
+    got = np.stack([np.asarray(x, np.float32) for x in step_logits], axis=1)
+    return float(np.abs(got - ref).max() / (1.0 + np.abs(ref).max()))
 
 
 if __name__ == "__main__":

@@ -119,7 +119,6 @@ def apply_moe(params: dict, x: jnp.ndarray, *, cfg: ArchConfig,
                         params["w_gate"], params["w_out"])
     else:
         from jax.sharding import PartitionSpec as P
-        from ..parallel.ops import shard_map_compat
         import numpy as np
         S = mesh.shape[model_axis]
         Eps = m.num_experts // S
@@ -220,10 +219,12 @@ def apply_moe(params: dict, x: jnp.ndarray, *, cfg: ArchConfig,
             wspec_in = wspec_out = P(model_axis)
         # batch-of-1 decode can't shard the token axis at all: replicate
         xspec = P(data_axes) if (n_tok % dp == 0 and n_tok >= dp) else P()
-        out = shard_map_compat(
+        # replication checking off: the psum/all_to_all bodies are not
+        # closed under it
+        out = jax.shard_map(
             body, mesh=mesh,
             in_specs=(xspec, P(), wspec_in, wspec_in, wspec_out),
-            out_specs=xspec,
+            out_specs=xspec, check_vma=False,
         )(xf, params["router"], params["w_in"], params["w_gate"],
           params["w_out"])
 

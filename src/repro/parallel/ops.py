@@ -15,22 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-__all__ = ["shard_map_compat", "sharded_embed", "token_nll"]
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions.
-
-    ``jax.shard_map`` (with ``check_vma``) only exists on newer jax; 0.4.x
-    ships it as ``jax.experimental.shard_map.shard_map`` with the equivalent
-    knob spelled ``check_rep``.  Replication checking is disabled either way
-    (the psum/all_to_all bodies here are not closed under it)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+__all__ = ["sharded_embed", "token_nll"]
 
 
 def sharded_embed(table: jnp.ndarray, tokens: jnp.ndarray,
@@ -58,10 +43,11 @@ def sharded_embed(table: jnp.ndarray, tokens: jnp.ndarray,
         return jax.lax.psum(out, model_axis)
 
     out_spec = P(daxes, None, None) if shardable else P(None, None, None)
-    return shard_map_compat(
+    # replication checking off: the psum body is not closed under it
+    return jax.shard_map(
         emb, mesh=mesh,
         in_specs=(P(model_axis, None), tok_spec),
-        out_specs=out_spec,
+        out_specs=out_spec, check_vma=False,
     )(table, tokens)
 
 

@@ -45,7 +45,7 @@ def serve_policy_sweep(bridge: CodedServingBridge, requests, policies,
     The model, jitted step functions and encoded layers are
     policy-independent, so only the admission config swaps between runs —
     the columns of the resulting reports are directly comparable.  With the
-    bridge's ``verify`` on (numpy backend), each run is asserted to decode
+    bridge's ``verify`` on (its default), each run is asserted to decode
     every coded matmul to the uncoded product.
     """
     from ..stream.queueing import AdmissionConfig
@@ -102,13 +102,18 @@ def run_coded_smoke(*, arch: str = "llama3.2-1b", smoke: bool = True,
                     coding_scope: str = "head",
                     steps_per_dispatch: int = 1,
                     execution: str = "batched",
-                    backend: str = "numpy", seed: int = 0,
+                    backend: str = "numpy", device_products: bool = False,
+                    parity_storage: str = "materialized", seed: int = 0,
                     trace=None, faults=None, ls_tail: bool = False,
                     verbose: bool = True):
     """Serve one synthetic workload under each admission policy.
 
     Returns 0 on success (CLI-friendly); asserts that every decoded coded
-    matmul matched the uncoded product (numpy backend).  ``trace`` writes
+    matmul matched the uncoded product and its greedy argmax.  ``arch`` is
+    a registry name or an ``ArchConfig`` (see
+    :func:`repro.launch.serve.build_model`); ``backend``,
+    ``device_products`` and ``parity_storage`` go to the bridge
+    unchanged.  ``trace`` writes
     a Chrome/Perfetto trace of the whole sweep (every policy's serve, as
     sibling "serve" spans) to that path.  ``faults`` (a fault spec string
     or :class:`repro.faults.FaultConfig`) arms the chaos layer —
@@ -123,12 +128,14 @@ def run_coded_smoke(*, arch: str = "llama3.2-1b", smoke: bool = True,
     tracer = None
     if trace:
         from ..obs import Tracer
-        tracer = Tracer(meta={"entry": "run_coded_smoke", "arch": arch,
+        tracer = Tracer(meta={"entry": "run_coded_smoke",
+                              "arch": getattr(arch, "name", arch),
                               "scope": coding_scope, "backend": backend,
                               "execution": execution})
     from ..stream import AdmissionConfig, StreamConfig
     bridge = CodedServingBridge(
         masters=masters, arch=arch, smoke=smoke, backend=backend,
+        device_products=device_products, parity_storage=parity_storage,
         config=StreamConfig(admission=AdmissionConfig(policy="edf"),
                             rng=seed),
         slots_per_master=slots_per_master, coding_scope=coding_scope,
@@ -140,11 +147,15 @@ def run_coded_smoke(*, arch: str = "llama3.2-1b", smoke: bool = True,
         prompt_len=prompt_len, gen_len=gen_len, rate=rate, seed=seed)
     reports = serve_policy_sweep(bridge, reqs, policies)
     if verbose:
-        print(f"[serve_coded] arch={arch} requests={n_requests} "
+        cfg = bridge._model["cfg"]
+        print(f"[serve_coded] arch={cfg.name} L={bridge.head.L} "
+              f"layers={cfg.n_layers} requests={n_requests} "
               f"gen={gen_len} masters={masters} "
               f"slots/master={slots_per_master} scope={coding_scope} "
               f"steps/dispatch={steps_per_dispatch} "
-              f"execution={execution} backend={backend}")
+              f"execution={execution} backend={backend} "
+              f"device_products={device_products} "
+              f"parity_storage={parity_storage}")
         print_policy_table(reports)
         if faults is not None:
             for policy, rep in reports.items():

@@ -311,11 +311,7 @@ class ServeReport:
     solve_steps: int
     execution: str = "batched"           # shard-execution engine
     decode_backend: str = "numpy"        # effective decode-solve engine
-    backend: str = "numpy"               # backend as *requested*
-    # backend that actually ran: CodedLinear warns and falls back to
-    # numpy when jax is unavailable — the report records the truth
-    # instead of echoing the request
-    backend_effective: str = "numpy"
+    backend: str = "numpy"               # encode / product backend
     parity_storage: str = "materialized"  # "materialized" | "virtual"
     redispatches: int = 0                # in-flight steps re-timed off-plan
     sim_horizon_ms: float = 0.0          # last step/request completion
@@ -404,9 +400,8 @@ class CodedServingBridge:
                host-side so tokens match the uncoded pipeline bit-for-bit
                — on-TPU serving flips this on and accepts float32
                verification tolerances.
-    backend:   "numpy" | "jax" | "pallas" for the coded encode/decode.
-               When jax is missing the layers warn and fall back to
-               numpy; ``ServeReport.backend_effective`` records what ran.
+    backend:   "numpy" | "jax" | "pallas" for the coded encode/decode
+               (``ServeReport.decode_backend`` names the solve's device).
     parity_storage: "materialized" keeps each layer's packed ``[W; WR]``
                encoded cache (and its float32 device mirror); "virtual"
                derives parity rows from packed threefry counters on
@@ -672,13 +667,10 @@ class CodedServingBridge:
             heapq.heappush(heap, (ev.time, next(seq), _CHURN, ev))
         stats = dict(max_err=0.0, match=0, total=0, solves=0, tokens=0,
                      redispatches=0)
-        # the decode-solve engine this configuration actually runs: jax and
-        # pallas both decode through the jitted solve, but CodedLinear
-        # warns and falls back to numpy when jax is unavailable — the
-        # report and the per-step log say what really ran, not what was
-        # asked (ServeReport.backend_effective carries the same truth)
+        # the decode-solve engine (and its device) this configuration
+        # runs: jax and pallas both decode through the jitted float64
+        # solve on the host CPU — the report and the per-step log say so
         eff_decode = ("local" if not self.coded
-                      else "numpy" if not bk.has_jax()
                       else DECODE_ENGINE[self.backend])
 
         # ---- fault layer (chaos + detect/quarantine/retry) ---------------
@@ -1465,7 +1457,7 @@ class CodedServingBridge:
                 "master": m, "scope": self.coding_scope,
                 "execution": self.execution,
                 "decode_backend": sp.decode_backend or eff_decode,
-                "backend": self.head.backend,   # effective, post-fallback
+                "backend": self.head.backend,
                 "parity_storage": self.parity_storage,
                 "t_start": sp.t_start, "t_done": t,
                 "batch": len(sp.tok_by_slot), "tokens": ntok,
@@ -1670,7 +1662,6 @@ class CodedServingBridge:
             execution=self.execution,
             decode_backend=eff_decode,
             backend=self.backend,
-            backend_effective=self.head.backend,
             parity_storage=self.parity_storage,
             redispatches=stats["redispatches"],
             sim_horizon_ms=max([metrics.t_end]

@@ -72,7 +72,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import warnings
 import zlib
 from typing import List, Optional
 
@@ -86,11 +85,12 @@ __all__ = ["CodedLinear", "CodedLMHead", "LinearStep", "HeadStep",
            "PrefixPlan", "shard_products", "prefix_plan_batch",
            "surplus_plan"]
 
-#: the decode solve engine each backend actually runs ("pallas" has encode
-#: and product kernels but no solve kernel — its decode runs the jitted
-#: jax solve, and benches report that honestly instead of silently
-#: relabelling it)
-DECODE_ENGINE = {"numpy": "numpy", "jax": "jax", "pallas": "jax"}
+#: the decode solve engine each backend actually runs, with its device
+#: ("pallas" has encode and product kernels but no solve kernel — its
+#: decode runs the jitted jax solve; that solve is float64 LU, which the
+#: TPU compiler does not implement, so it runs on the host CPU device
+#: named by :func:`repro.stream.backend.decode_device`)
+DECODE_ENGINE = {"numpy": "numpy", "jax": "jax:cpu", "pallas": "jax:cpu"}
 
 #: smallest mixed-row parity solve block (see ``prefix_plan``): blocks
 #: below this swap in extra delivered parity rows for the last systematic
@@ -295,8 +295,7 @@ class CodedLinear:
     name: label used by the bridge's step log ("head", "blk0.wq", ...).
     seed: parity-generator seed (one layer = one generator stream).
     backend: "numpy" | "jax" | "pallas" for the parity encode + decode
-    solve.  If jax is unavailable the layer *warns* and falls back to
-    numpy — ``requested_backend`` keeps the ask, ``backend`` the truth.
+    solve (``decode_backend`` names the solve engine and its device).
     parity_storage: "materialized" caches ``[W; WR]`` rows; "virtual"
     derives parity from packed threefry counters on demand (module
     docstring).
@@ -307,14 +306,6 @@ class CodedLinear:
                  parity_chunk: int = 256,
                  parity_storage: str = "materialized"):
         bk.check_backend(backend)
-        self.requested_backend = backend
-        if backend != "numpy" and not bk.has_jax():
-            warnings.warn(
-                f"CodedLinear({name!r}): backend {backend!r} requested but "
-                "jax is not importable — falling back to backend='numpy' "
-                "(float64 encode/decode; slower, tighter numerics)",
-                RuntimeWarning, stacklevel=2)
-            backend = "numpy"
         if parity_storage not in ("materialized", "virtual"):
             raise ValueError(
                 f"parity_storage must be 'materialized' or 'virtual', "
@@ -392,7 +383,10 @@ class CodedLinear:
             from ..kernels import ops
             return np.asarray(ops.matmul(R_dev, self._W_dev),
                               dtype=np.float64)
-        return np.asarray(R_dev @ self._W_dev, dtype=np.float64)
+        from ..kernels.matmul import F32_PRECISION
+        return np.asarray(jnp.matmul(R_dev, self._W_dev,
+                                     precision=F32_PRECISION),
+                          dtype=np.float64)
 
     def _grow_enc(self, n_new: int) -> None:
         need = self._n_enc + n_new

@@ -48,7 +48,7 @@ import numpy as np
 
 from ..obs import current_tracer
 from ..stream import backend as bk
-from .coded_linear import CodedLinear, shard_products
+from .coded_linear import DECODE_ENGINE, CodedLinear, shard_products
 
 __all__ = ["ShardProblem", "PackedShards", "PackedStage",
            "pack_shard_problems"]
@@ -327,10 +327,8 @@ class PackedStage:
         else:
             self.problems = list(problems)
         self.backend = backend
-        # the decode-solve engine this stage will actually run (jax falls
-        # back to numpy when unavailable) — the bridge logs it per step
-        self.solve_backend = "jax" if (backend != "numpy"
-                                       and bk.has_jax()) else "numpy"
+        # the decode-solve engine this stage runs — the bridge logs it
+        self.solve_backend = DECODE_ENGINE[backend]
         self.pack = pack_shard_problems(self.problems, tile=tile)
         # decode groups: (offset problem index, L, member count, subgroups)
         self.groups: List[Tuple[int, int, int, List[_DecodeGroup]]] = []
@@ -391,8 +389,7 @@ class PackedStage:
                                    np.asarray(X, dtype=np.float64))
         if mutate is not None:
             mutate(Y)
-        use_jax = self.solve_backend == "jax"
-        solve = bk.solve_jax if use_jax else None
+        solve = bk.solve_jax if self.solve_backend != "numpy" else None
         out: Dict[str, np.ndarray] = {}
         B = Y.shape[-1]
         off = self.pack.offsets

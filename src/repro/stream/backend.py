@@ -71,6 +71,7 @@ __all__ = [
     "localize_faulty_worker",
     "solve_stacked",
     "solve_jax",
+    "decode_device",
     "StackedLU",
     "ExponentialBlock",
 ]
@@ -96,7 +97,7 @@ def has_jax() -> bool:
 
 
 def _use_jax(backend: str) -> bool:
-    return backend in ("jax", "pallas") and has_jax()
+    return backend in ("jax", "pallas")
 
 
 @functools.lru_cache(maxsize=1)
@@ -473,48 +474,49 @@ def simulate_batch(l, k, b, a, u, gamma, L, trials: int, *,
 # ---------------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=1)
+def decode_device():
+    """The device the jax decode solves run on: the host CPU, by name.
+
+    The exact decode needs float64 LU, and the TPU compiler implements
+    ``LuDecomposition`` for F32/C64 only — so the solve is placed on the
+    CPU explicitly, also when an accelerator is the default device, and
+    never runs in float32.  Raises where JAX has no CPU backend (a
+    ``JAX_PLATFORMS`` that lists platforms must list ``cpu`` too, after
+    the accelerator: ``tpu,cpu``).
+    """
+    import jax
+    try:
+        return jax.devices("cpu")[0]
+    except RuntimeError as e:
+        raise RuntimeError(
+            "the jax decode solve needs JAX's CPU backend for float64 LU; "
+            f"JAX_PLATFORMS={jax.config.jax_platforms!r} leaves it out "
+            "(use e.g. 'tpu,cpu')") from e
+
+
+@functools.lru_cache(maxsize=1)
 def _solve_jit():
     import jax
     import jax.numpy as jnp
     return jax.jit(lambda Gs, y: jnp.linalg.solve(Gs, y))
 
 
-@functools.lru_cache(maxsize=1)
-def _solve_jit_x64():
-    """Jitted float64 stacked solve, or None when x64 jit is unavailable.
-
-    Probed once: under ``jax.experimental.enable_x64`` the jit traces
-    float64 avals, so the decode solve keeps full precision on the jax
-    path instead of silently truncating to float32.  Builds where the
-    context manager is missing or the output still canonicalises to f32
-    fall back to the f32 jit (the historical behaviour).
-    """
+def _on_decode_device(*arrays):
     import jax
-    import jax.numpy as jnp
-    try:
-        fn = jax.jit(lambda Gs, y: jnp.linalg.solve(Gs, y))
-        with jax.experimental.enable_x64():
-            out = fn(jnp.eye(2, dtype=jnp.float64)[None],
-                     jnp.ones((1, 2, 1), jnp.float64))
-            if out.dtype != jnp.float64:
-                return None
-        return fn
-    except Exception:  # pragma: no cover - older jax without enable_x64
-        return None
+    dev = decode_device()
+    return tuple(jax.device_put(np.asarray(a, dtype=np.float64), dev)
+                 for a in arrays)
 
 
 def solve_jax(A: np.ndarray, b: np.ndarray) -> np.ndarray:
-    """Stacked solve on the jitted jax path, float64 when the build allows.
+    """Stacked float64 solve on the jitted jax path (:func:`decode_device`).
 
-    The call must re-enter ``enable_x64`` every time: jit avals
+    The call must enter ``jax.enable_x64`` every time: jit avals
     canonicalise by the flag's state at trace *and* call time.
     """
-    fn = _solve_jit_x64()
-    if fn is None:
-        return np.asarray(_solve_jit()(A, b))
     import jax
-    with jax.experimental.enable_x64():
-        return np.asarray(fn(A, b))
+    with jax.enable_x64(True):
+        return np.asarray(_solve_jit()(*_on_decode_device(A, b)))
 
 
 try:                                   # the gufunc behind np.linalg.solve
@@ -920,12 +922,9 @@ class LSDecodePlan:
             out = self._lu.solve(y)
         elif _use_jax(backend):
             import jax
-            try:
-                with jax.experimental.enable_x64():
-                    out = np.asarray(_lstsq_jit()(self.Gs, y),
-                                     dtype=np.float64)
-            except Exception:     # pragma: no cover - lstsq not vmappable
-                out = self._apply_np(y)
+            with jax.enable_x64(True):
+                out = np.asarray(_lstsq_jit()(
+                    *_on_decode_device(self.Gs, y)), dtype=np.float64)
         else:
             out = self._apply_np(y)
         if tr is not None:

@@ -174,6 +174,39 @@ def test_decode_jax_matches_numpy():
                                x_true, rtol=1e-4, atol=1e-4)
 
 
+def test_jax_decode_solve_is_float64_on_named_cpu_device():
+    """float64 LU does not compile for the TPU, so the jax decode engine
+    places its solve on the host CPU by name and keeps full precision."""
+    import jax
+    from repro.stream.backend import decode_device, solve_jax
+    assert decode_device() == jax.devices("cpu")[0]
+    rng = np.random.default_rng(0)
+    A = rng.normal(size=(3, 12, 12)) + 4 * np.eye(12)
+    b = rng.normal(size=(3, 12, 2))
+    out = solve_jax(A, b)
+    assert out.dtype == np.float64
+    np.testing.assert_allclose(out, np.linalg.solve(A, b), rtol=1e-12,
+                               atol=1e-12)
+
+
+def test_decode_device_refuses_a_platform_list_without_cpu(monkeypatch):
+    """No silent fallback: without JAX's CPU backend the jax decode engine
+    raises and says how to keep it."""
+    import jax
+    from repro.stream import backend as bk
+
+    def no_cpu(*_a):
+        raise RuntimeError("Unknown backend cpu")
+
+    bk.decode_device.cache_clear()
+    monkeypatch.setattr(jax, "devices", no_cpu)
+    try:
+        with pytest.raises(RuntimeError, match="tpu,cpu"):
+            bk.decode_device()
+    finally:
+        bk.decode_device.cache_clear()
+
+
 # ---------------------------------------------------------------------------
 # simulate_batch / simulate_plan(backend="jax")
 # ---------------------------------------------------------------------------

@@ -145,15 +145,15 @@ def test_decode_backend_recorded_explicitly():
     W = rng.normal(size=(32, 8))
     l_int = np.array([16, 16, 16])
     finish = np.array([1.0, 2.0, 3.0])
-    for backend, engine in (("numpy", "numpy"), ("jax", "jax"),
-                            ("pallas", "jax")):
+    for backend, engine in (("numpy", "numpy"), ("jax", "jax:cpu"),
+                            ("pallas", "jax:cpu")):
         lin = CodedLinear(W, name="t", seed=0, backend=backend)
         res = lin.step(rng.normal(size=(2, 8)), l_int, finish, 3.0)
         assert lin.decode_backend == engine
         assert res.decode_backend == engine
     rep = _serve("head", backend="pallas")
-    assert rep.decode_backend == "jax"
-    assert all(s["decode_backend"] == "jax" for s in rep.steps)
+    assert rep.decode_backend == "jax:cpu"
+    assert all(s["decode_backend"] == "jax:cpu" for s in rep.steps)
     rep = _serve("head", coded=False)
     assert rep.decode_backend == "local"
 

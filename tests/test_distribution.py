@@ -39,7 +39,8 @@ for arch in ["llama3_2_1b", "dbrx_132b", "rwkv6_7b", "jamba_1_5_large_398b"]:
 
     ref = model_fwd(params, batch, cfg=cfg)["logits"]
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     ctx = ModelCtx(mesh=mesh, model_axis="model")
     p_shard = param_shardings(jax.eval_shape(lambda: params), mesh)
     params_s = jax.device_put(params, p_shard)

@@ -16,6 +16,7 @@ import pytest
 from repro.core import mds
 from repro.serve_coded import (CODING_SCOPES, CodedLinear,
                                CodedServingBridge, synthetic_requests)
+from repro.serve_coded.coded_linear import DECODE_ENGINE
 from repro.serve_coded.packing import PackedStage, ShardProblem
 from repro.stream import AdmissionConfig
 from repro.stream import backend as bk
@@ -60,10 +61,10 @@ def test_virtual_serving_token_identical(scope, backend):
     assert mat.parity_storage == "materialized"
     # satellite: the report says which backend actually ran
     assert virt.backend == backend
-    assert virt.backend_effective == (backend if bk.has_jax() else "numpy")
+    assert virt.decode_backend == DECODE_ENGINE[backend]
     for s in virt.steps:
         assert s["parity_storage"] == "virtual"
-        assert s["backend"] == virt.backend_effective
+        assert s["backend"] == backend
 
 
 # ---------------------------------------------------------------------------
@@ -174,6 +175,40 @@ def test_kernel_generator_bit_equals_host_derivation():
     assert np.array_equal(host, dev)
 
 
+EDGE_BITS = np.array([0, 255, 256, (1 << 24) - 1, 1 << 24, (1 << 31) - 1,
+                      1 << 31, (1 << 32) - 1], dtype=np.uint32)
+
+
+def test_uniform24_host_jnp_kernel_bits_on_edge_counters():
+    """``_uniform24`` converts through int32 (the TPU kernel compiler has
+    no uint32 → float32 cast); host, jitted jnp and a Pallas kernel must
+    give the same float32 bits, including at the uint32 extremes."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    host = mds._uniform24(EDGE_BITS)
+    assert host.dtype == np.float32
+    assert host[0] == 0.0 and host[-1] == np.float32(1 - 2.0 ** -24)
+    bits = jnp.asarray(np.broadcast_to(EDGE_BITS, (8, 8)))
+    via_jnp = np.asarray(jax.jit(mds._uniform24)(bits))[0]
+
+    def kern(b_ref, o_ref):
+        o_ref[...] = mds._uniform24(b_ref[...])
+    via_kernel = np.asarray(pl.pallas_call(
+        kern, out_shape=jax.ShapeDtypeStruct((8, 8), jnp.float32),
+        interpret=True)(bits))[0]
+    for got in (via_jnp, via_kernel):
+        assert np.array_equal(got.view(np.uint32), host.view(np.uint32))
+    # the same edge values as packed row counters: kernel rows ≡ host rows
+    from repro.kernels import ops
+    key = (0xFFFFFFFF, 0)
+    host_rows = mds.counter_parity_rows(key, EDGE_BITS, 64,
+                                        dtype=np.float32)
+    dev_rows = np.asarray(ops.counter_parity_rows(key, 64, EDGE_BITS,
+                                                  interpret=True))
+    assert np.array_equal(dev_rows.view(np.uint32),
+                          host_rows.view(np.uint32))
+
+
 def test_fused_generation_kernel_matches_xla_twin():
     """The TPU-path fused kernel (R derived in-VMEM, tile contraction)
     agrees with the XLA twin `gen_parity_products` routes to off-TPU —
@@ -205,23 +240,25 @@ def test_fused_generation_kernel_matches_xla_twin():
 
 
 # ---------------------------------------------------------------------------
-# Satellite: the silent backend downgrade now warns and is recorded
+# The backend is never downgraded: the layer runs what was asked
 # ---------------------------------------------------------------------------
 
 def test_backend_fallback_warns_and_records(monkeypatch):
+    # jax is a hard dependency: even a has_jax() probe that says "no" must
+    # not turn a pallas request into a silent numpy layer
     monkeypatch.setattr(bk, "has_jax", lambda: False)
-    with pytest.warns(RuntimeWarning, match="falling back"):
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
         lin = CodedLinear(np.eye(8), name="nb", backend="pallas")
-    assert lin.backend == "numpy"
-    assert lin.requested_backend == "pallas"
-    assert lin.decode_backend == "numpy"
+    assert lin.backend == "pallas"
+    assert lin.decode_backend == "jax:cpu"
 
 
 def test_backend_kept_when_jax_present():
     with warnings.catch_warnings():
         warnings.simplefilter("error")
         lin = CodedLinear(np.eye(8), name="ok", backend="jax")
-    assert lin.backend == "jax" and lin.requested_backend == "jax"
+    assert lin.backend == "jax" and lin.decode_backend == "jax:cpu"
     with pytest.raises(ValueError):
         CodedLinear(np.eye(8), parity_storage="sparse")
 
