@@ -11,7 +11,9 @@ object, which Perfetto loads directly):
   clocks are comparable;
 * tracks (``"wall"``, ``"sim:worker3"``) become named threads via ``"M"``
   metadata events;
-* counters are ``ph:"C"`` events on their domain's pid.
+* counters are ``ph:"C"`` events on their domain's pid;
+* a span's ``parent`` (the enclosing span's ``seq``) and its own ``seq``
+  go into ``args``.
 
 The exported object also carries ``repro_summary`` (the :func:`summary`
 rollup) and ``repro_meta`` — Perfetto ignores unknown top-level keys, and
@@ -71,9 +73,8 @@ def to_chrome_trace(tr: Tracer) -> Dict[str, Any]:
             "name": sp.name, "cat": sp.cat, "ph": "X",
             "ts": sp.t0 * scale, "dur": sp.dur * scale,
             "pid": pid, "tid": tids.tid(pid, lane),
+            "args": dict(sp.args or {}, seq=sp.seq, parent=sp.parent),
         }
-        if sp.args:
-            ev["args"] = sp.args
         events.append(ev)
     for sp in tr.instants:
         pid, lane = _split_track(sp.track)
@@ -104,6 +105,7 @@ def to_records(tr: Tracer) -> List[Dict[str, Any]]:
             row: Dict[str, Any] = {
                 "kind": kind, "seq": sp.seq, "name": sp.name, "cat": sp.cat,
                 "track": sp.track, "t0": sp.t0, "t1": sp.t1, "dur": sp.dur,
+                "parent": sp.parent,
             }
             for k, v in (sp.args or {}).items():
                 row[f"arg_{k}"] = v
@@ -121,6 +123,9 @@ def summary(tr: Tracer, top_k: int = 5) -> Dict[str, Any]:
 
     * ``per_stage_wall`` — wall seconds per leaf stage category
       (plan / pack / kernel / decode / glue);
+    * ``per_cat_wall`` — wall seconds per category over every wall-domain
+      span, the detail lane's sub-spans included (a nested span counts
+      under its own category and again under its parent's);
     * ``step_wall_total`` / ``stage_coverage`` — parent "step" span total and
       the fraction of it the leaf stages account for (the acceptance
       criterion wants ≥ 0.9);
@@ -128,10 +133,12 @@ def summary(tr: Tracer, top_k: int = 5) -> Dict[str, Any]:
       (worker, task) attribution rows.
     """
     per_stage = {cat: 0.0 for cat in STAGE_CATS}
+    per_cat: Dict[str, float] = {}
     step_total = 0.0
     deliveries: List[Span] = []
     for sp in tr.spans:
         if _is_wall(sp):
+            per_cat[sp.cat] = per_cat.get(sp.cat, 0.0) + sp.dur
             if sp.cat in per_stage:
                 per_stage[sp.cat] += sp.dur
             elif sp.cat == "step":
@@ -155,6 +162,7 @@ def summary(tr: Tracer, top_k: int = 5) -> Dict[str, Any]:
     counters.update({f"{k}_peak": v for k, v in tr.gauge_peaks.items()})
     return {
         "per_stage_wall": per_stage,
+        "per_cat_wall": per_cat,
         "step_wall_total": step_total,
         "stage_wall_total": stage_sum,
         "stage_coverage": (stage_sum / step_total) if step_total > 0 else None,

@@ -7,10 +7,9 @@ wall span covers the device work.  The fence only happens when a tracer is
 actually recording: with tracing off the async dispatch pipeline is
 untouched (that's the < 2% disabled-overhead contract).
 
-:func:`profiler_annotation` optionally nests a
-``jax.profiler.TraceAnnotation`` so spans line up with a concurrently
-captured device profile (``Tracer(jax_profiler=True)``); it is a no-op
-without jax or when the tracer doesn't ask for it.
+With ``Tracer(jax_profiler=True)`` the span is also a
+``jax.profiler.TraceAnnotation`` (every context span of such a tracer
+is), so it lines up with a concurrently captured device profile.
 """
 from __future__ import annotations
 
@@ -19,7 +18,7 @@ from typing import Any, Dict, Iterator, Optional
 
 from .tracer import Tracer, current_tracer
 
-__all__ = ["device_fence", "device_span", "profiler_annotation"]
+__all__ = ["device_fence", "device_span"]
 
 
 def device_fence(x: Any) -> Any:
@@ -48,22 +47,6 @@ class _Fence:
 
 
 @contextlib.contextmanager
-def profiler_annotation(name: str,
-                        tr: Optional[Tracer] = None) -> Iterator[None]:
-    tr = tr if tr is not None else current_tracer()
-    if tr is None or not tr.jax_profiler:
-        yield
-        return
-    try:
-        from jax.profiler import TraceAnnotation
-    except Exception:
-        yield
-        return
-    with TraceAnnotation(name):
-        yield
-
-
-@contextlib.contextmanager
 def device_span(name: str, *, cat: str = "kernel", track: str = "wall",
                 args: Optional[Dict[str, Any]] = None,
                 tr: Optional[Tracer] = None) -> Iterator[_Fence]:
@@ -79,9 +62,8 @@ def device_span(name: str, *, cat: str = "kernel", track: str = "wall",
     if tr is None:
         yield fence
         return
-    with profiler_annotation(name, tr):
-        with tr.span(name, cat=cat, track=track, args=args) as a:
-            yield fence
-            if fence.value is not None:
-                device_fence(fence.value)
-            a.setdefault("fenced", fence.value is not None)
+    with tr.span(name, cat=cat, track=track, args=args) as a:
+        yield fence
+        if fence.value is not None:
+            device_fence(fence.value)
+        a.setdefault("fenced", fence.value is not None)

@@ -23,6 +23,21 @@ exactly the no-tracer path (one predicate at entry).  Deep call sites
 (kernels, backend solves) consult the process-global :func:`current_tracer`,
 which is ``None`` unless a caller installed an enabled tracer via
 :func:`use_tracer` — again one global read + ``is None`` check when off.
+
+Hierarchy: every span carries ``parent``, the ``seq`` of the context span
+that was open when it started (``None`` for a root), so the spans of a
+run form a tree.  Sequence numbers are given at exit, so a child's
+``parent`` is filled in when its parent closes.
+
+Detail lane: sub-spans of a stage (the decode's right-hand side, solve
+and scatter, the trunk call, parity derivation) go on the
+:data:`DETAIL_TRACK` lane with categories outside :data:`STAGE_CATS`, so
+the stage rollup and the glue backfill see exactly the stage spans.
+
+Profiler clock: with ``jax_profiler=True`` every context span also enters
+a ``jax.profiler.TraceAnnotation`` of its name, so a concurrently
+captured profile shows the same spans, nested the same way, on the
+trace's clock.
 """
 from __future__ import annotations
 
@@ -33,11 +48,19 @@ from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 __all__ = [
     "Span", "Tracer", "current_tracer", "use_tracer", "STAGE_CATS",
+    "DETAIL_TRACK", "NULL_SPAN",
 ]
 
 # Leaf stage categories whose wall durations are expected to tile a serving
 # step ("step" spans are their parents; coverage = sum(stages)/sum(steps)).
 STAGE_CATS = ("plan", "pack", "kernel", "decode", "glue")
+
+# Wall-clock lane of the sub-spans inside stages (see module docstring).
+DETAIL_TRACK = "wall:detail"
+
+# Shared no-op context for instrumented sites with no tracer installed:
+# ``with (tr.span(...) if tr is not None else NULL_SPAN):``.
+NULL_SPAN = contextlib.nullcontext()
 
 
 @dataclasses.dataclass
@@ -51,6 +74,7 @@ class Span:
     t0: float
     t1: float
     args: Optional[Dict[str, Any]] = None
+    parent: Optional[int] = None     # seq of the enclosing context span
 
     @property
     def dur(self) -> float:
@@ -64,9 +88,16 @@ class Tracer:
     def __init__(self, *, enabled: bool = True, jax_profiler: bool = False,
                  meta: Optional[Dict[str, Any]] = None):
         self.enabled = bool(enabled)
-        # Annotate jitted regions with jax.profiler.TraceAnnotation so a
+        # Enter a jax.profiler.TraceAnnotation per context span so a
         # concurrently-captured device profile lines up with our spans.
         self.jax_profiler = bool(jax_profiler)
+        self._annotation = None
+        if self.jax_profiler:
+            try:
+                from jax.profiler import TraceAnnotation
+                self._annotation = TraceAnnotation
+            except Exception:  # no jax: spans are recorded all the same
+                pass
         self.meta: Dict[str, Any] = dict(meta or {})
         self.epoch = time.perf_counter()
         self.spans: List[Span] = []
@@ -75,6 +106,9 @@ class Tracer:
         self.gauge_peaks: Dict[str, float] = {}  # max level per gauge
         self.counter_samples: List[Tuple[str, str, float, float]] = []
         self._seq = 0
+        # open context spans, innermost last: each frame collects the
+        # spans started inside it, whose ``parent`` it fills in at exit
+        self._open: List[List[Span]] = []
 
     # -- clock ---------------------------------------------------------------
 
@@ -88,12 +122,20 @@ class Tracer:
         self._seq += 1
         return self._seq
 
+    def _adopt(self, sp: Span) -> None:
+        """Make ``sp`` a child of the innermost open context span."""
+        if self._open:
+            self._open[-1].append(sp)
+
     def add_span(self, name: str, t0: float, t1: float, *, cat: str = "misc",
                  track: str = "sim",
-                 args: Optional[Dict[str, Any]] = None) -> Optional[Span]:
+                 args: Optional[Dict[str, Any]] = None,
+                 parent: Optional[int] = None) -> Optional[Span]:
         """Record an interval with explicit endpoints (sim-time spans, or
         wall spans measured externally).  Non-finite endpoints are dropped —
-        a lost delivery (finish = inf) has no extent to draw."""
+        a lost delivery (finish = inf) has no extent to draw.  ``parent``
+        is the enclosing span's ``seq``; by default the open context
+        span."""
         if not self.enabled:
             return None
         if not (t0 == t0 and t1 == t1 and t0 != float("inf")
@@ -102,7 +144,9 @@ class Tracer:
             return None
         if t1 < t0:
             t0, t1 = t1, t0
-        sp = Span(self._next_seq(), name, cat, track, t0, t1, args)
+        sp = Span(self._next_seq(), name, cat, track, t0, t1, args, parent)
+        if parent is None:
+            self._adopt(sp)
         self.spans.append(sp)
         return sp
 
@@ -114,14 +158,24 @@ class Tracer:
         if not self.enabled:
             yield {}
             return
+        ann = self._annotation(name) if self._annotation is not None \
+            else NULL_SPAN
         a: Dict[str, Any] = dict(args) if args else {}
-        t0 = self.now()
-        try:
-            yield a
-        finally:
-            t1 = self.now()
-            self.spans.append(Span(self._next_seq(), name, cat, track,
-                                   t0, t1, a or None))
+        children: List[Span] = []
+        with ann:
+            self._open.append(children)
+            t0 = self.now()
+            try:
+                yield a
+            finally:
+                t1 = self.now()
+                self._open.pop()
+                sp = Span(self._next_seq(), name, cat, track, t0, t1,
+                          a or None)
+                for c in children:
+                    c.parent = sp.seq
+                self._adopt(sp)
+                self.spans.append(sp)
 
     def instant(self, name: str, t: Optional[float] = None, *,
                 cat: str = "event", track: str = "wall",
@@ -131,8 +185,9 @@ class Tracer:
         tt = self.now() if t is None else float(t)
         if tt != tt or tt in (float("inf"), float("-inf")):
             return
-        self.instants.append(Span(self._next_seq(), name, cat, track,
-                                  tt, tt, args))
+        sp = Span(self._next_seq(), name, cat, track, tt, tt, args)
+        self._adopt(sp)
+        self.instants.append(sp)
 
     def count(self, name: str, delta: float = 1, *,
               t: Optional[float] = None, track: str = "wall") -> None:

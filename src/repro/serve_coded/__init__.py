@@ -16,7 +16,7 @@ See ``src/repro/stream/README.md`` (serving-bridge section) for the
 architecture, the coding-scope table and the admission-policy table.
 """
 from .bridge import (CODING_SCOPES, EXECUTION_MODES, CodedServingBridge,
-                     ServeReport, default_pool)
+                     ServeReport, StepInfo, default_pool)
 from .coded_linear import (CodedLinear, CodedLMHead, HeadStep, LinearStep,
                            PrefixPlan, prefix_plan_batch, shard_products)
 from .packing import PackedShards, PackedStage, ShardProblem
@@ -25,8 +25,8 @@ from .requests import ServeRequest, synthetic_requests
 from .trunk import HostTrunk, trunk_matmul_keys
 
 __all__ = [
-    "CodedServingBridge", "ServeReport", "default_pool", "CODING_SCOPES",
-    "EXECUTION_MODES",
+    "CodedServingBridge", "ServeReport", "StepInfo", "default_pool",
+    "CODING_SCOPES", "EXECUTION_MODES",
     "CodedLMHead", "HeadStep", "CodedLinear", "LinearStep", "PrefixPlan",
     "prefix_plan_batch", "shard_products",
     "PackedShards", "PackedStage", "ShardProblem",

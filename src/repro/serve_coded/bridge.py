@@ -62,12 +62,13 @@ import heapq
 import itertools
 import math
 import time
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from ..faults import FaultConfig, QuarantineLedger, corrupt_products
-from ..obs import STAGE_CATS, Tracer, current_tracer, use_tracer
+from ..obs import (DETAIL_TRACK, NULL_SPAN, STAGE_CATS, Tracer,
+                   current_tracer, use_tracer)
 from ..parallel.hetero import coded_row_shards, rescaled_row_shards
 from ..sim.cluster import ClusterProfile, ec2_cluster
 from ..stream import backend as bk
@@ -86,7 +87,7 @@ from .plan_cache import StepPlan, StepPlanCache
 from .requests import ServeRequest
 from .trunk import HostTrunk, trunk_matmul_keys
 
-__all__ = ["CodedServingBridge", "ServeReport", "default_pool",
+__all__ = ["CodedServingBridge", "ServeReport", "StepInfo", "default_pool",
            "CODING_SCOPES", "EXECUTION_MODES"]
 
 _ARRIVE, _CHURN, _STEP, _RETRY = "arrive", "churn", "step", "retry"
@@ -110,7 +111,8 @@ def _fill_glue(tr, n0: int) -> None:
     ``cat="glue"`` spans — the host forward math and bookkeeping between
     coded stages — so the stage categories tile the step and
     ``stage_coverage`` stays an honest ≈1 instead of silently shrinking as
-    more of a step's time hides between instrumented calls."""
+    more of a step's time hides between instrumented calls.  Sub-spans on
+    the detail lane are not leaves; each glue span's parent is the step."""
     if tr is None or not tr.spans:
         return
     parent = tr.spans[-1]
@@ -123,12 +125,14 @@ def _fill_glue(tr, n0: int) -> None:
             continue
         if a > cur:
             tr.add_span(f"glue:{parent.name}#{n}", cur, a, cat="glue",
-                        track="wall", args={"step": parent.name})
+                        track="wall", args={"step": parent.name},
+                        parent=parent.seq)
             n += 1
         cur = max(cur, b)
     if parent.t1 > cur:
         tr.add_span(f"glue:{parent.name}#{n}", cur, parent.t1, cat="glue",
-                    track="wall", args={"step": parent.name})
+                    track="wall", args={"step": parent.name},
+                    parent=parent.seq)
 
 
 class _BarrierExecutor:
@@ -293,6 +297,19 @@ class _MasterState:
         self.slots: Dict[int, _Slot] = {}
         self.free: List[int] = list(range(n_slots))
         self.step: Optional[_Step] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class StepInfo:
+    """What :meth:`CodedServingBridge.serve`'s ``on_step`` hook is told
+    before each trunk call of an executed coded step: the master, and per
+    slot served, the request id, the sequence position of the token the
+    step produces (the prompt length for a prefill, the fed token's
+    position plus one otherwise) and whether the slot is prefilled."""
+    master: int
+    rids: Tuple[int, ...]
+    positions: Tuple[int, ...]
+    prefill: Tuple[bool, ...]
 
 
 @dataclasses.dataclass
@@ -589,19 +606,26 @@ class CodedServingBridge:
 
     def serve(self, requests: Sequence[ServeRequest],
               churn: Sequence[WorkerEvent] = (), *,
-              trace_path: Optional[str] = None) -> ServeReport:
+              trace_path: Optional[str] = None,
+              on_step: Optional[Callable[[StepInfo], None]] = None
+              ) -> ServeReport:
         """Serve ``requests`` to completion (see class docstring).
 
         ``trace_path`` (needs a recording ``tracer``) writes the run's
         Chrome/Perfetto trace JSON there after the event loop drains; the
         report's ``per_stage_wall`` / ``trace_path`` fields are filled in
-        either way when a tracer is attached."""
+        either way when a tracer is attached.
+
+        ``on_step`` is called with a :class:`StepInfo` before each trunk
+        call of every executed coded step (once per step unless
+        ``steps_per_dispatch`` > 1).  An exception it raises propagates
+        out of ``serve()``: that is how a caller ends a serve early."""
         # re-normalize (callers may assign .tracer after construction):
         # a disabled tracer serves on the identical uninstrumented branch
         tracer = self.tracer \
             if self.tracer is not None and self.tracer.enabled else None
         if tracer is None:
-            return self._serve_impl(requests, churn)
+            return self._serve_impl(requests, churn, on_step)
         with use_tracer(tracer) as tr:
             with tr.span("serve", cat="run",
                          args={"scope": self.coding_scope,
@@ -609,7 +633,7 @@ class CodedServingBridge:
                                "backend": self.backend,
                                "coded": self.coded,
                                "requests": len(requests)}):
-                rep = self._serve_impl(requests, churn)
+                rep = self._serve_impl(requests, churn, on_step)
         rep.per_stage_wall = dict(tr.summary()["per_stage_wall"])
         if trace_path is not None:
             rep.trace_path = str(trace_path)
@@ -617,7 +641,9 @@ class CodedServingBridge:
         return rep
 
     def _serve_impl(self, requests: Sequence[ServeRequest],
-                    churn: Sequence[WorkerEvent] = ()) -> ServeReport:
+                    churn: Sequence[WorkerEvent] = (),
+                    on_step: Optional[Callable[[StepInfo], None]] = None
+                    ) -> ServeReport:
         t_wall = time.perf_counter()
         reqs = {r.rid: r for r in requests}
         max_len = max(len(r.prompt) + r.gen_len for r in requests) + 8
@@ -771,7 +797,11 @@ class CodedServingBridge:
 
         def hidden_states_jit(st: _MasterState, slot_ids: List[int]
                               ) -> np.ndarray:
+            # the trunk programs are called from here, directly under
+            # _execute_step: callers that wrap decode_fn / prefill_fn read
+            # these locals (st, cont, slot) and the caller's (m, sp)
             import jax.numpy as jnp
+            tr = current_tracer()
             cont = [s for s in slot_ids if not st.slots[s].needs_prefill]
             H: Dict[int, np.ndarray] = {}
             if cont:
@@ -781,10 +811,15 @@ class CodedServingBridge:
                 for s in cont:
                     toks[s, 0] = st.slots[s].tokens[-1]
                     pos[s] = st.slots[s].pos
-                _, st.caches, hid = mdl["decode_fn"](
-                    mdl["params"], jnp.asarray(toks), jnp.asarray(pos),
-                    st.caches)
-                hid = np.asarray(hid, dtype=np.float64)
+                # the span ends when the host holds the hidden states,
+                # so it covers the wait on the device's decode
+                with (tr.span("trunk.decode", cat="trunk",
+                              track=DETAIL_TRACK, args={"slots": len(cont)})
+                      if tr is not None else NULL_SPAN):
+                    _, st.caches, hid = mdl["decode_fn"](
+                        mdl["params"], jnp.asarray(toks), jnp.asarray(pos),
+                        st.caches)
+                    hid = np.asarray(hid, dtype=np.float64)
                 for s in cont:
                     H[s] = hid[s, 0]
                     st.slots[s].pos += 1
@@ -792,13 +827,18 @@ class CodedServingBridge:
                 slot = st.slots[s]
                 if not slot.needs_prefill:
                     continue
-                batch = {"tokens": jnp.asarray(slot.prompt[None])}
-                _, c1, h1 = mdl["prefill_fn"](
-                    mdl["params"], batch, mdl["zero_caches"](1))
-                st.caches = self._write_slot(st.caches, c1, s)
-                slot.pos = len(slot.prompt)
-                slot.needs_prefill = False
-                H[s] = np.asarray(h1, dtype=np.float64)[0, 0]
+                with (tr.span("trunk.prefill", cat="trunk",
+                              track=DETAIL_TRACK,
+                              args={"rid": slot.rid,
+                                    "prompt": len(slot.prompt)})
+                      if tr is not None else NULL_SPAN):
+                    batch = {"tokens": jnp.asarray(slot.prompt[None])}
+                    _, c1, h1 = mdl["prefill_fn"](
+                        mdl["params"], batch, mdl["zero_caches"](1))
+                    st.caches = self._write_slot(st.caches, c1, s)
+                    slot.pos = len(slot.prompt)
+                    slot.needs_prefill = False
+                    H[s] = np.asarray(h1, dtype=np.float64)[0, 0]
             return np.stack([H[s] for s in slot_ids])
 
         def hidden_states_host(st: _MasterState, slot_ids: List[int],
@@ -954,6 +994,8 @@ class CodedServingBridge:
                          args={"master": m, "execution": self.execution,
                                "scope": self.coding_scope}) as a:
                 _execute_step(m, sp)
+                a["rids"] = [states[m].slots[s].rid
+                             for s in sorted(sp.tok_by_slot)]
                 a["tokens"] = sum(len(v) for v in sp.tok_by_slot.values())
                 a["used_solve"] = sp.used_solve
             # the wall time between this step's stage spans is measured,
@@ -1282,6 +1324,13 @@ class CodedServingBridge:
                             < st.slots[s].gen_len]
                 if not slot_ids:
                     break
+                if on_step is not None:
+                    sl = [st.slots[s] for s in slot_ids]
+                    on_step(StepInfo(
+                        master=m, rids=tuple(x.rid for x in sl),
+                        positions=tuple(len(x.prompt) if x.needs_prefill
+                                        else x.pos + 1 for x in sl),
+                        prefill=tuple(x.needs_prefill for x in sl)))
                 if self.coding_scope == "head":
                     H = hidden_states_jit(st, slot_ids)
                 elif batched:

@@ -78,7 +78,7 @@ from typing import List, Optional
 import numpy as np
 
 from ..core import mds
-from ..obs import current_tracer
+from ..obs import DETAIL_TRACK, NULL_SPAN, current_tracer
 from ..stream import backend as bk
 
 __all__ = ["CodedLinear", "CodedLMHead", "LinearStep", "HeadStep",
@@ -413,26 +413,44 @@ class CodedLinear:
         regardless of when, or in what growth order, the block is first
         needed.  That growth-history independence is the replay bug fix:
         the old sequential ``default_rng`` stream gave row r different
-        values depending on how the cache had grown before it."""
+        values depending on how the cache had grown before it.
+
+        While a tracer records, a memo miss is a ``parity.derive`` span
+        with a ``parity.cond`` span per guard evaluation, and counts
+        ``parity_blocks_derived`` and ``parity_redraws``."""
         blk = self._block_memo.get(b)
         if blk is not None:
             self._memo_put(self._block_memo, b, blk)   # refresh LRU slot
             return blk
-        ids = np.arange(b * self.parity_chunk, (b + 1) * self.parity_chunk)
-        draw = self._block_draws.get(b)
-        if draw is None:
-            draw = 0
-            blk = mds.counter_parity_rows(
-                self.pkey, mds.parity_counters(ids, draw), self.L)
-            while mds.parity_cond(blk) > mds.PARITY_COND_LIMIT:
-                draw += 1
-                self.parity_redraws += 1
+        tr = current_tracer()
+        if tr is not None:
+            tr.count("parity_blocks_derived")
+        with (tr.span("parity.derive", cat="parity.derive",
+                      track=DETAIL_TRACK, args={"layer": self.name,
+                                                "block": int(b)})
+              if tr is not None else NULL_SPAN):
+            ids = np.arange(b * self.parity_chunk,
+                            (b + 1) * self.parity_chunk)
+            draw = self._block_draws.get(b)
+            if draw is None:
+                draw = 0
+                while True:
+                    blk = mds.counter_parity_rows(
+                        self.pkey, mds.parity_counters(ids, draw), self.L)
+                    with (tr.span("parity.cond", cat="parity.cond",
+                                  track=DETAIL_TRACK)
+                          if tr is not None else NULL_SPAN):
+                        cond = mds.parity_cond(blk)
+                    if not cond > mds.PARITY_COND_LIMIT:
+                        break
+                    draw += 1
+                    self.parity_redraws += 1
+                    if tr is not None:
+                        tr.count("parity_redraws")
+                self._block_draws[b] = draw
+            else:
                 blk = mds.counter_parity_rows(
                     self.pkey, mds.parity_counters(ids, draw), self.L)
-            self._block_draws[b] = draw
-        else:
-            blk = mds.counter_parity_rows(
-                self.pkey, mds.parity_counters(ids, draw), self.L)
         self._memo_put(self._block_memo, b, blk)
         return blk
 

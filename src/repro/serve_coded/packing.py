@@ -46,7 +46,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..obs import current_tracer
+from ..obs import DETAIL_TRACK, NULL_SPAN, current_tracer
 from ..stream import backend as bk
 from .coded_linear import DECODE_ENGINE, CodedLinear, shard_products
 
@@ -272,39 +272,51 @@ class _DecodeGroup:
             [Rg[j][:, self.unk[j]]
              for j in range(gs)]))                          # (gs, L-s, L-s)
 
-    def apply(self, yg: np.ndarray, z: np.ndarray, solve) -> None:
+    def apply(self, yg: np.ndarray, z: np.ndarray, solve, tr=None) -> None:
         """Decode this group's slice of the stacked products into ``z``.
 
         ``solve=None`` runs the numpy path through the group's cached LU
         factors (getrf once per frozen plan, getrs per step); a callable
-        (the jitted jax solve) gets the raw stacked systems."""
+        (the jitted jax solve) gets the raw stacked systems.  With a
+        tracer ``tr``, the right-hand side, the solve and the scatter are
+        ``decode.rhs`` / ``decode.solve`` / ``decode.scatter`` sub-spans
+        on the detail lane."""
         if self.perm:
-            z[self.sel[:, None], self.rows] = yg[self.sel]
+            with (tr.span("decode.scatter", cat="decode.scatter",
+                          track=DETAIL_TRACK) if tr is not None
+                  else NULL_SPAN):
+                z[self.sel[:, None], self.rows] = yg[self.sel]
             return
-        if self.sel.size == 1:
-            # dominant serving case: 1D gathers + a 2D gemm gather the
-            # same values as the stacked path below (one dgemm either
-            # way), minus the broadcast-index overhead per call
-            y0 = yg[self.sel[0]]
-            sys_y = y0[self.sys_pos[0]]
-            par_y = y0[self.par_pos[0]]
-            rhs = (par_y - self.Gk[0] @ sys_y)[None]
+        g = self.sel.size
+        with (tr.span("decode.rhs", cat="decode.rhs", track=DETAIL_TRACK)
+              if tr is not None else NULL_SPAN):
+            if g == 1:
+                # dominant serving case: 1D gathers + a 2D gemm gather the
+                # same values as the stacked path below (one dgemm either
+                # way), minus the broadcast-index overhead per call
+                y0 = yg[self.sel[0]]
+                sys_y = y0[self.sys_pos[0]]
+                rhs = (y0[self.par_pos[0]] - self.Gk[0] @ sys_y)[None]
+            else:
+                ys = yg[self.sel]
+                g_ar = np.arange(g)[:, None]
+                sys_y = ys[g_ar, self.sys_pos]
+                rhs = ys[g_ar, self.par_pos] - self.Gk @ sys_y
+        with (tr.span("decode.solve", cat="decode.solve", track=DETAIL_TRACK,
+                      args={"systems": g, "order": int(rhs.shape[1])})
+              if tr is not None else NULL_SPAN):
             sol = self.lu.solve(rhs) if solve is None \
                 else solve(self.lu.A, rhs)
-            z0 = z[self.sel[0]]
-            z0[self.sys_rows[0]] = sys_y                     # exact pins
-            z0[self.unk[0]] = sol[0]
-            return
-        sel2 = self.sel[:, None]
-        ys = yg[self.sel]
-        g_ar = np.arange(self.sel.size)[:, None]
-        sys_y = ys[g_ar, self.sys_pos]
-        par_y = ys[g_ar, self.par_pos]
-        rhs = par_y - self.Gk @ sys_y
-        sol = self.lu.solve(rhs) if solve is None \
-            else solve(self.lu.A, rhs)
-        z[sel2, self.sys_rows] = sys_y                       # exact pins
-        z[sel2, self.unk] = sol
+        with (tr.span("decode.scatter", cat="decode.scatter",
+                      track=DETAIL_TRACK) if tr is not None else NULL_SPAN):
+            if g == 1:
+                z0 = z[self.sel[0]]
+                z0[self.sys_rows[0]] = sys_y                 # exact pins
+                z0[self.unk[0]] = sol[0]
+            else:
+                sel2 = self.sel[:, None]
+                z[sel2, self.sys_rows] = sys_y               # exact pins
+                z[sel2, self.unk] = sol
 
 
 class PackedStage:
@@ -402,7 +414,7 @@ class PackedStage:
                 yg = Y[off[i0]:off[i0] + g * L].reshape(g, L, B)  # a view
                 z = np.empty((g, L, B))
                 for sub in subs:
-                    sub.apply(yg, z, solve)
+                    sub.apply(yg, z, solve, tr)
                 for j in range(g):
                     out[self.problems[i0 + j].key] = z[j].T
         return out

@@ -49,7 +49,7 @@ from typing import Optional
 
 import numpy as np
 
-from ..obs import current_tracer, device_span
+from ..obs import DETAIL_TRACK, NULL_SPAN, current_tracer, device_span
 
 __all__ = [
     "has_jax",
@@ -512,11 +512,21 @@ def solve_jax(A: np.ndarray, b: np.ndarray) -> np.ndarray:
     """Stacked float64 solve on the jitted jax path (:func:`decode_device`).
 
     The call must enter ``jax.enable_x64`` every time: jit avals
-    canonicalise by the flag's state at trace *and* call time.
+    canonicalise by the flag's state at trace *and* call time.  It keeps
+    no factors: each call factorises all ``g`` stacked systems afresh
+    (counted as ``decode_lu_factorizations`` while a tracer records).
     """
     import jax
+    tr = current_tracer()
+    if tr is not None:
+        g, n = A.shape[0], A.shape[1]
+        tr.count("decode_lu_factorizations", g)
+        tr.count("decode_system_rows", g * n)
     with jax.enable_x64(True):
-        return np.asarray(_solve_jit()(*_on_decode_device(A, b)))
+        with (tr.span("decode.put", cat="decode.put", track=DETAIL_TRACK)
+              if tr is not None else NULL_SPAN):
+            args = _on_decode_device(A, b)
+        return np.asarray(_solve_jit()(*args))
 
 
 try:                                   # the gufunc behind np.linalg.solve
@@ -554,6 +564,12 @@ class StackedLU:
         self._checked = False
 
     def solve(self, b: np.ndarray) -> np.ndarray:
+        tr = current_tracer()
+        if tr is not None:
+            g, n = self.A.shape[0], self.A.shape[1]
+            tr.count("decode_system_rows", g * n)
+            if _lu_factor is None or self._fac is None:
+                tr.count("decode_lu_factorizations", g)
         if _lu_factor is None:
             return solve_stacked(self.A, b)
         if self._fac is None:

@@ -12,6 +12,7 @@ The tracer's contracts under test:
   event carries the required ``name/ph/ts/pid/tid`` keys (``dur`` on
   complete events), with wall and sim time as separate pid groups.
 """
+import gc
 import json
 import time
 
@@ -69,6 +70,40 @@ def test_span_nesting_and_ordering_deterministic():
             pass
     assert [(s.name, s.cat) for s in tr.spans] == \
         [(s.name, s.cat) for s in tr2.spans]
+
+
+def test_parent_links_follow_the_open_span():
+    tr = Tracer()
+    with tr.span("outer", cat="step"):
+        with tr.span("inner", cat="plan"):
+            tr.add_span("measured", tr.now(), tr.now(), cat="decode",
+                        track="wall")
+            tr.instant("mark")
+        tr.add_span("pinned", 0.0, 1.0, parent=7)
+    measured, inner, pinned, outer = tr.spans
+    assert outer.parent is None
+    assert inner.parent == outer.seq and measured.parent == inner.seq
+    assert tr.instants[0].parent == inner.seq
+    assert pinned.parent == 7                    # explicit parent kept
+    ev = {e["name"]: e for e in tr.to_chrome_trace()["traceEvents"]
+          if e["ph"] == "X"}
+    assert ev["inner"]["args"]["parent"] == outer.seq
+    assert ev["outer"]["args"]["seq"] == outer.seq
+    assert ev["outer"]["args"]["parent"] is None
+    assert {r["name"]: r["parent"] for r in tr.to_records()}["inner"] \
+        == outer.seq
+
+
+def test_summary_per_cat_wall_covers_every_wall_lane():
+    tr = Tracer()
+    tr.add_span("a", 0.0, 1.0, cat="decode", track="wall")
+    tr.add_span("b", 0.25, 0.75, cat="decode.solve", track="wall:detail")
+    tr.add_span("c", 0.0, 5.0, cat="decode.solve", track="sim")
+    s = tr.summary()
+    assert s["per_cat_wall"] == {"decode": 1.0, "decode.solve": 0.5}
+    # the detail lane's category is not a stage: the rollup is unchanged
+    assert s["per_stage_wall"]["decode"] == 1.0
+    assert s["stage_wall_total"] == 1.0
 
 
 def test_add_span_sanitizes_endpoints():
@@ -171,19 +206,26 @@ def test_summary_rolls_stages_counters_and_stragglers():
 
 def test_disabled_tracer_overhead_under_2pct_on_1k_task_stream():
     """An attached-but-disabled tracer must serve the identical code path:
-    best-of-3 wall time within 2% (plus a small absolute slack for timer
-    granularity) of the no-tracer run on a 1k-task stream."""
-    def best(tracer_factory, reps=3):
-        b = float("inf")
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            _run_stream(tracer_factory(), max_tasks=1000)
-            b = min(b, time.perf_counter() - t0)
-        return b
+    best-of-5 wall time within 2% (plus a small absolute slack for timer
+    granularity) of the no-tracer run on a 1k-task stream.  The two arms
+    alternate run by run, each going first in every other pair, so a load
+    change on a shared host (other test workers) falls on both arms alike
+    instead of on one; a full collection before each run keeps the garbage
+    of earlier tests from landing on one arm."""
+    def timed(tracer):
+        gc.collect()
+        t0 = time.perf_counter()
+        _run_stream(tracer, max_tasks=1000)
+        return time.perf_counter() - t0
 
-    best(lambda: None, reps=1)                   # warm caches/jit once
-    t_none = best(lambda: None)
-    t_disabled = best(lambda: Tracer(enabled=False))
+    timed(None)                                  # warm caches/jit once
+    best = {"none": float("inf"), "disabled": float("inf")}
+    for i in range(5):
+        arms = ("none", "disabled") if i % 2 == 0 else ("disabled", "none")
+        for arm in arms:
+            t = timed(None if arm == "none" else Tracer(enabled=False))
+            best[arm] = min(best[arm], t)
+    t_none, t_disabled = best["none"], best["disabled"]
     assert t_disabled <= t_none * 1.02 + 0.05, (t_disabled, t_none)
 
 
