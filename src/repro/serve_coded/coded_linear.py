@@ -85,11 +85,14 @@ __all__ = ["CodedLinear", "CodedLMHead", "LinearStep", "HeadStep",
            "PrefixPlan", "shard_products", "prefix_plan_batch",
            "surplus_plan"]
 
-#: the decode solve engine each backend actually runs, with its device
-#: ("pallas" has encode and product kernels but no solve kernel — its
-#: decode runs the jitted jax solve; that solve is float64 LU, which the
-#: TPU compiler does not implement, so it runs on the host CPU device
-#: named by :func:`repro.stream.backend.decode_device`)
+#: the decode solve engine each backend runs, with its device ("pallas"
+#: has encode and product kernels but no solve kernel — its decode is the
+#: jax engine's).  Every engine decodes in float64 on the host CPU: square
+#: solves go through :class:`repro.stream.backend.StackedLU`, so a frozen
+#: packed stage factorises once and solves each step with LAPACK getrs;
+#: the jax engine's least-squares decode runs jitted on the CPU device
+#: named by :func:`repro.stream.backend.decode_device`, since the TPU
+#: compiler implements no float64 factorisation
 DECODE_ENGINE = {"numpy": "numpy", "jax": "jax:cpu", "pallas": "jax:cpu"}
 
 #: smallest mixed-row parity solve block (see ``prefix_plan``): blocks

@@ -215,8 +215,9 @@ class _DecodeGroup:
     derivation, no dense generator), and every index set is one
     fancy-index array.  Per-item
     solve inputs are value-identical to the serial engine's, and LAPACK's
-    ``gesv`` is deterministic per matrix, so the decoded outputs match the
-    serial path bit-for-bit on numpy regardless of how tasks are stacked.
+    ``getrf`` + ``getrs`` is deterministic per matrix, so the decoded
+    outputs match the serial path bit-for-bit regardless of how tasks are
+    stacked.
     """
 
     __slots__ = ("sel", "perm", "rows", "sys_pos", "par_pos", "sys_rows",
@@ -272,15 +273,15 @@ class _DecodeGroup:
             [Rg[j][:, self.unk[j]]
              for j in range(gs)]))                          # (gs, L-s, L-s)
 
-    def apply(self, yg: np.ndarray, z: np.ndarray, solve, tr=None) -> None:
+    def apply(self, yg: np.ndarray, z: np.ndarray, tr=None) -> None:
         """Decode this group's slice of the stacked products into ``z``.
 
-        ``solve=None`` runs the numpy path through the group's cached LU
-        factors (getrf once per frozen plan, getrs per step); a callable
-        (the jitted jax solve) gets the raw stacked systems.  With a
-        tracer ``tr``, the right-hand side, the solve and the scatter are
-        ``decode.rhs`` / ``decode.solve`` / ``decode.scatter`` sub-spans
-        on the detail lane."""
+        Every engine solves through the group's cached float64 LU factors
+        on the host: getrf on the first solve of a frozen stage, one
+        LAPACK getrs per step after that.  With a tracer ``tr``, the
+        right-hand side, the solve and the scatter are ``decode.rhs`` /
+        ``decode.solve`` / ``decode.scatter`` sub-spans on the detail
+        lane."""
         if self.perm:
             with (tr.span("decode.scatter", cat="decode.scatter",
                           track=DETAIL_TRACK) if tr is not None
@@ -305,8 +306,7 @@ class _DecodeGroup:
         with (tr.span("decode.solve", cat="decode.solve", track=DETAIL_TRACK,
                       args={"systems": g, "order": int(rhs.shape[1])})
               if tr is not None else NULL_SPAN):
-            sol = self.lu.solve(rhs) if solve is None \
-                else solve(self.lu.A, rhs)
+            sol = self.lu.solve(rhs)
         with (tr.span("decode.scatter", cat="decode.scatter",
                       track=DETAIL_TRACK) if tr is not None else NULL_SPAN):
             if g == 1:
@@ -401,7 +401,6 @@ class PackedStage:
                                    np.asarray(X, dtype=np.float64))
         if mutate is not None:
             mutate(Y)
-        solve = bk.solve_jax if self.solve_backend != "numpy" else None
         out: Dict[str, np.ndarray] = {}
         B = Y.shape[-1]
         off = self.pack.offsets
@@ -414,7 +413,7 @@ class PackedStage:
                 yg = Y[off[i0]:off[i0] + g * L].reshape(g, L, B)  # a view
                 z = np.empty((g, L, B))
                 for sub in subs:
-                    sub.apply(yg, z, solve, tr)
+                    sub.apply(yg, z, tr)
                 for j in range(g):
                     out[self.problems[i0 + j].key] = z[j].T
         return out

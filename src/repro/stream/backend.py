@@ -509,12 +509,17 @@ def _on_decode_device(*arrays):
 
 
 def solve_jax(A: np.ndarray, b: np.ndarray) -> np.ndarray:
-    """Stacked float64 solve on the jitted jax path (:func:`decode_device`).
+    """One-shot stacked float64 solve, jitted on :func:`decode_device`.
 
-    The call must enter ``jax.enable_x64`` every time: jit avals
-    canonicalise by the flag's state at trace *and* call time.  It keeps
-    no factors: each call factorises all ``g`` stacked systems afresh
-    (counted as ``decode_lu_factorizations`` while a tracer records).
+    It keeps no factors: each call factorises all ``g`` stacked systems
+    afresh (counted as ``decode_lu_factorizations`` while a tracer
+    records).  No decode calls it: every engine's square solves go
+    through :class:`StackedLU`, which factorises a frozen structure once.
+    It stays as the jax reference those cached solves are checked
+    against, bit for bit wherever the right-hand side has two or more
+    columns (with one, XLA's triangular solve rounds differently in the
+    last bits).  The call must enter ``jax.enable_x64`` every time: jit
+    avals canonicalise by the flag's state at trace *and* call time.
     """
     import jax
     tr = current_tracer()
@@ -548,12 +553,14 @@ class StackedLU:
 
     ``np.linalg.solve`` (LAPACK ``gesv``) re-factorizes on every call.  A
     *frozen* decode plan solves the same parity sub-blocks for every step
-    of a serve with only the right-hand side changing, so the ``getrf``
-    is paid once and each step replays the O(n²) ``getrs``.  Solutions
-    are bit-identical to :func:`solve_stacked` — ``gesv`` *is*
-    ``getrf`` + ``getrs`` — and both decode engines route through this,
-    so they cannot drift from each other.  Falls back to the one-shot
-    solve when scipy is unavailable.
+    of a serve with only the right-hand side changing, so the ``getrf`` is
+    paid on the first solve and each later one replays the O(n²)
+    ``getrs``.  Solutions are bit-identical to :func:`solve_stacked` —
+    ``gesv`` *is* ``getrf`` + ``getrs`` — and every engine's exact square
+    decode routes through this, so the engines cannot drift from each
+    other.  The factors replace the matrices: ``A`` is dropped once
+    factorised, so a cached structure holds one n² block per system.
+    Falls back to the one-shot solve when scipy is unavailable.
     """
 
     __slots__ = ("A", "_fac", "_checked")
@@ -566,21 +573,22 @@ class StackedLU:
     def solve(self, b: np.ndarray) -> np.ndarray:
         tr = current_tracer()
         if tr is not None:
-            g, n = self.A.shape[0], self.A.shape[1]
+            g, n = b.shape[0], b.shape[1]
             tr.count("decode_system_rows", g * n)
-            if _lu_factor is None or self._fac is None:
+            if self._fac is None:
                 tr.count("decode_lu_factorizations", g)
         if _lu_factor is None:
             return solve_stacked(self.A, b)
         if self._fac is None:
             self._fac = [_lu_factor(a, check_finite=False) for a in self.A]
+            self.A = None
         # raw getrs: same triangular sweeps as lu_solve minus its per-call
         # argument validation (thousands of tiny serving solves per run)
         if len(self._fac) == 1:
             lu, piv = self._fac[0]
             out = _dgetrs(lu, piv, b[0])[0][None]
         else:
-            out = np.empty(self.A.shape[:1] + b.shape[1:])
+            out = np.empty(b.shape)
             for i, (lu, piv) in enumerate(self._fac):
                 out[i] = _dgetrs(lu, piv, b[i])[0]
         # singularity is a property of the frozen matrices, not the RHS —
@@ -693,10 +701,6 @@ class _MixedGroup:
         self.sys_pos = sys_pos        # (g, s) receive positions of sys rows
         self.par_pos = par_pos        # (g, L-s) receive positions of parity
 
-    @property
-    def A(self) -> np.ndarray:
-        return self.lu.A
-
 
 class DecodePlan:
     """The X-independent structure of one stacked exactly-L decode.
@@ -712,8 +716,8 @@ class DecodePlan:
     ``plan_decode(G, rows).apply(y)``, so the two can never drift.
     """
 
-    __slots__ = ("B", "L", "fast_idx", "fast_rows", "full_idx", "full_G",
-                 "full_lu", "mixed_groups")
+    __slots__ = ("B", "L", "fast_idx", "fast_rows", "full_idx", "full_lu",
+                 "mixed_groups")
 
     def __init__(self, B: int, L: int, fast_idx, fast_rows, full_idx,
                  full_G, mixed_groups):
@@ -722,8 +726,8 @@ class DecodePlan:
         self.fast_idx = fast_idx          # (f,) tasks decoded by scatter
         self.fast_rows = fast_rows        # (f, L) their received row ids
         self.full_idx = full_idx          # (n,) tasks needing the full solve
-        self.full_G = full_G              # (n, L, L) gathered generators
-        self.full_lu = StackedLU(full_G)  # factor cached across applies
+        # (n, L, L) gathered generators, factorised on the first apply
+        self.full_lu = StackedLU(full_G)
         # list of (grp_idx, sys_rows, unk, A, Gk) per distinct s count
         self.mixed_groups = mixed_groups
 
@@ -738,28 +742,20 @@ class DecodePlan:
         if squeeze:
             y = y[..., None]
         out = np.empty((self.B, self.L, y.shape[-1]))
-
-        use_jax = _use_jax(backend)
-
-        def solve(lu: StackedLU, b: np.ndarray) -> np.ndarray:
-            # jax path: the jitted batched solve; numpy path: the cached
-            # getrf + per-step getrs (bit-identical to gesv)
-            if use_jax:
-                return solve_jax(lu.A, b)
-            return lu.solve(b)
-
+        # every engine solves through the cached getrf + per-apply getrs
+        # (bit-identical to gesv)
         if self.fast_idx.size:
             # permutation decode: out[b, rows[b, i]] = y[b, i]
             out[self.fast_idx[:, None], self.fast_rows] = y[self.fast_idx]
         if self.full_idx.size:
-            out[self.full_idx] = solve(self.full_lu, y[self.full_idx])
+            out[self.full_idx] = self.full_lu.solve(y[self.full_idx])
         for mg in self.mixed_groups:
             # receive-order partitions were frozen at plan time as position
             # index arrays; partition y the same row-major way
             yg = y[mg.grp]
             sys_y = np.take_along_axis(yg, mg.sys_pos[:, :, None], axis=1)
             par_y = np.take_along_axis(yg, mg.par_pos[:, :, None], axis=1)
-            sol = solve(mg.lu, par_y - mg.Gk @ sys_y)
+            sol = mg.lu.solve(par_y - mg.Gk @ sys_y)
             out[mg.grp[:, None], mg.sys_rows] = sys_y        # exact pins
             out[mg.grp[:, None], mg.unk] = sol
         if tr is not None:
@@ -876,8 +872,8 @@ def decode_batch(G: np.ndarray, rows: np.ndarray, y: np.ndarray,
     ``identity_prefix=True`` skips the O(L²) identity-prefix scan when the
     caller built G systematically (see :func:`plan_decode`).
 
-    Solves run as ``np.linalg.solve`` on the numpy backend and a cached
-    jitted ``jnp.linalg.solve`` on jax/pallas.  This function is the
+    Solves run through :class:`StackedLU` (LAPACK ``getrf`` + ``getrs``
+    in float64 on the host) on every backend.  This function is the
     composition ``plan_decode(G, rows).apply(y)``; callers re-decoding
     against fixed received rows should hold the plan and call ``apply``.
     """

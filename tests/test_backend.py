@@ -3,6 +3,8 @@ Pallas must agree on ``completion_times``, ``decode_batch`` (dense and the
 systematic fast path), ``simulate_batch`` statistics, and full
 ``CodedExecutor.run`` reports on fixed seeds.
 """
+import warnings
+
 import numpy as np
 import pytest
 
@@ -205,6 +207,40 @@ def test_decode_device_refuses_a_platform_list_without_cpu(monkeypatch):
             bk.decode_device()
     finally:
         bk.decode_device.cache_clear()
+
+
+@pytest.mark.parametrize("g", (1, 3))
+def test_cached_lu_matches_the_jitted_solve_and_factorises_once(g):
+    """The jax engine's square decodes solve through ``StackedLU``: each
+    solve equals the one-shot jitted ``solve_jax`` bit for bit, and only
+    the first one factorises."""
+    from repro.obs import Tracer, use_tracer
+    from repro.stream.backend import StackedLU, solve_jax
+    rng = np.random.default_rng(g)
+    n = 40
+    A = rng.normal(size=(g, n, n))
+    lu = StackedLU(A.copy())
+    with use_tracer(tr := Tracer()):
+        for step in range(2):
+            b = rng.normal(size=(g, n, 4))
+            assert (lu.solve(b) == solve_jax(A, b)).all(), step
+            if step == 0:
+                # solve_jax counts its own factorisation: one each
+                assert tr.counters["decode_lu_factorizations"] == 2 * g
+    assert tr.counters["decode_lu_factorizations"] == 3 * g
+    assert tr.counters["decode_system_rows"] == 4 * g * n
+    # a single column rounds differently in XLA's triangular solve; the
+    # cached path stays LAPACK's gesv, bit for bit
+    b1 = rng.normal(size=(g, n, 1))
+    assert (lu.solve(b1) == np.linalg.solve(A, b1)).all()
+    np.testing.assert_allclose(lu.solve(b1), solve_jax(A, b1), rtol=1e-12,
+                               atol=1e-12)
+    # singularity is still caught on the first solve
+    S = A.copy()
+    S[-1, :, 0] = 0.0
+    with warnings.catch_warnings(), pytest.raises(np.linalg.LinAlgError):
+        warnings.simplefilter("ignore")       # scipy's zero-pivot warning
+        StackedLU(S).solve(rng.normal(size=(g, n, 4)))
 
 
 # ---------------------------------------------------------------------------

@@ -119,6 +119,54 @@ def test_batched_churn_mass_leave_redispatch_matches_serial():
     assert bat.decode_ok
 
 
+def test_pallas_frozen_factors_decode_bit_identical_to_refactorising():
+    """A frozen packed stage factorises its parity blocks once and solves
+    every later step with the cached factors: the decoded logits and the
+    tokens are bit-identical to factorising afresh on every step."""
+
+    class Fresh(bk.StackedLU):
+        __slots__ = ("keep",)
+
+        def __init__(self, A):
+            super().__init__(A)
+            self.keep = A
+
+        def solve(self, b):
+            self.A, self._fac, self._checked = self.keep, None, False
+            return super().solve(b)
+
+    def run(lu_cls):
+        logits, factorised = [], []
+        execute, factor = PackedStage.execute, bk._lu_factor
+
+        def keep(self, X, **kw):
+            out = execute(self, X, **kw)
+            logits.append({k: v.copy() for k, v in out.items()})
+            return out
+
+        def counting(a, **kw):
+            factorised.append(a.shape)
+            return factor(a, **kw)
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(bk, "StackedLU", lu_cls)
+            mp.setattr(bk, "_lu_factor", counting)
+            mp.setattr(PackedStage, "execute", keep)
+            rep = _serve("head", backend="pallas", n=4, gen=4)
+        return rep, logits, len(factorised)
+
+    reused, reused_logits, n_reused = run(bk.StackedLU)
+    fresh, fresh_logits, n_fresh = run(Fresh)
+    assert 0 < n_reused < n_fresh          # the replayed steps reused
+    assert reused.plan_cache_hits > 0
+    assert reused.tokens == fresh.tokens
+    assert reused.max_err == fresh.max_err
+    assert len(reused_logits) == len(fresh_logits)
+    for a, b in zip(reused_logits, fresh_logits):
+        assert a.keys() == b.keys()
+        for k in a:
+            assert (a[k] == b[k]).all(), k           # exact, not close
+
+
 def test_batched_multi_token_dispatch_reuses_plans():
     b4 = _serve("trunk", steps=4, n=4, gen=4)
     s4 = _serve("trunk", execution="serial", steps=4, n=4, gen=4)

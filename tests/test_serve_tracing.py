@@ -2,9 +2,9 @@
 and parity counters, the profiler annotations and the per-step hook.
 
 A tiny coded-head serve on the ``jax`` backend runs the same decode
-engine as the Pallas cells (the jitted float64 solve on the CPU device,
-``jax:cpu``), so the sub-spans and counters under test are the ones the
-benchmark's cells record.
+engine as the Pallas cells (``jax:cpu``: float64 LU factors cached per
+frozen block, one LAPACK solve a step), so the sub-spans and counters
+under test are the ones the benchmark's cells record.
 """
 import glob
 
@@ -18,10 +18,10 @@ from repro.stream import AdmissionConfig
 from repro.stream import backend as bk
 
 
-def _bridge(backend="jax", gen=4):
+def _bridge(backend="jax", gen=4, **kw):
     b = CodedServingBridge(masters=2, seed=0, slots_per_master=2,
                            coding_scope="head", backend=backend,
-                           admission=AdmissionConfig(policy="edf"))
+                           admission=AdmissionConfig(policy="edf"), **kw)
     b._setup_model(16 + gen + 8)
     return b
 
@@ -31,26 +31,36 @@ def _reqs(b, gen=4):
                               prompt_len=16, gen_len=gen, rate=0.02, seed=0)
 
 
+def _counting_solves(monkeypatch):
+    """Record the shape of every ``StackedLU.solve`` right-hand side, and
+    every system LAPACK actually factorises, in a dict of two lists."""
+    seen = {"solves": [], "factorised": []}
+    solve, factor = bk.StackedLU.solve, bk._lu_factor
+
+    def counting_solve(self, rhs):
+        seen["solves"].append(rhs.shape[:2])
+        return solve(self, rhs)
+
+    def counting_factor(a, **kw):
+        seen["factorised"].append(a.shape)
+        return factor(a, **kw)
+    monkeypatch.setattr(bk.StackedLU, "solve", counting_solve)
+    monkeypatch.setattr(bk, "_lu_factor", counting_factor)
+    return seen
+
+
 @pytest.fixture(scope="module")
 def traced():
-    """One traced serve, with every ``solve_jax`` call counted and every
-    ``on_step`` call kept."""
+    """One traced serve, with every solve of the packed decode and every
+    factorisation it does counted, and every ``on_step`` call kept."""
     b = _bridge()
     reqs = _reqs(b)
-    calls = []
-    inner = bk.solve_jax
-
-    def counting(A, rhs):
-        calls.append(A.shape)
-        return inner(A, rhs)
-    bk.solve_jax = counting
     infos = []
-    try:
+    with pytest.MonkeyPatch.context() as mp:
+        seen = _counting_solves(mp)
         b.tracer = tr = Tracer()
         rep = b.serve(reqs, on_step=infos.append)
-    finally:
-        bk.solve_jax = inner
-    return tr, rep, calls, infos
+    return tr, rep, seen, infos
 
 
 def _descendants(tr, root):
@@ -66,11 +76,14 @@ def _descendants(tr, root):
 
 
 def test_detail_spans_leave_stage_rollup_and_glue_unchanged(traced):
-    tr, _rep, _calls, _infos = traced
+    tr, _rep, _seen, _infos = traced
     detail = [sp for sp in tr.spans if sp.track == DETAIL_TRACK]
-    assert {sp.name for sp in detail} >= {
-        "decode.rhs", "decode.solve", "decode.put", "decode.scatter",
+    names = {sp.name for sp in detail}
+    assert names >= {
+        "decode.rhs", "decode.solve", "decode.scatter",
         "trunk.decode", "trunk.prefill", "parity.derive", "parity.cond"}
+    # the packed decode solves on the host: nothing is put on a device
+    assert "decode.put" not in names
     plain = Tracer()
     plain.spans = [sp for sp in tr.spans if sp.track != DETAIL_TRACK]
     assert plain.summary()["per_stage_wall"] == \
@@ -92,7 +105,7 @@ def test_detail_spans_leave_stage_rollup_and_glue_unchanged(traced):
 
 
 def test_parent_links_form_one_tree_rooted_at_serve(traced):
-    tr, _rep, _calls, _infos = traced
+    tr, _rep, _seen, _infos = traced
     by_seq = {sp.seq: sp for sp in tr.spans}
     roots = [sp for sp in tr.spans if sp.parent is None]
     assert [(sp.name, sp.cat) for sp in roots] == [("serve", "run")]
@@ -121,11 +134,12 @@ def test_parent_links_form_one_tree_rooted_at_serve(traced):
 
 
 def test_decode_counters_match_the_solves(traced):
-    tr, _rep, calls, _infos = traced
+    tr, _rep, seen, _infos = traced
+    calls = seen["solves"]
     assert calls, "the jax engine solved no system"
     c = tr.counters
-    assert c["decode_lu_factorizations"] == sum(s[0] for s in calls)
-    assert c["decode_system_rows"] == sum(s[0] * s[1] for s in calls)
+    assert c["decode_lu_factorizations"] == len(seen["factorised"]) > 0
+    assert c["decode_system_rows"] == sum(g * n for g, n in calls)
     solves = [sp for sp in tr.spans if sp.name == "decode.solve"]
     assert len(solves) == len(calls)
     assert sum(sp.args["systems"] * sp.args["order"] for sp in solves) \
@@ -137,8 +151,9 @@ def test_decode_counters_match_the_solves(traced):
         == c["parity_blocks_derived"] + c.get("parity_redraws", 0)
 
 
-def test_numpy_engine_reuses_factors_on_a_current_plan_cache():
-    b = _bridge(backend="numpy")
+@pytest.mark.parametrize("backend", ("numpy", "pallas"))
+def test_engine_reuses_factors_on_a_current_plan_cache(backend):
+    b = _bridge(backend=backend)
     reqs = _reqs(b)
     b.tracer = first = Tracer()
     b.serve(reqs)
@@ -152,8 +167,23 @@ def test_numpy_engine_reuses_factors_on_a_current_plan_cache():
     assert again.counters.get("parity_blocks_derived", 0) == 0
 
 
+def test_pallas_engine_factorises_every_step_without_a_plan_cache(
+        monkeypatch):
+    b = _bridge(backend="pallas", plan_cache=False)
+    seen = _counting_solves(monkeypatch)
+    b.tracer = tr = Tracer()
+    rep = b.serve(_reqs(b))
+    assert rep.plan_cache_misses == 0 and rep.plan_cache_hits == 0
+    # a fresh structure every step: each solve factorises its systems
+    solved = {sp.parent for sp in tr.spans if sp.name == "decode.solve"}
+    assert len(solved) > 1
+    assert len(seen["factorised"]) == sum(g for g, _n in seen["solves"])
+    assert tr.counters["decode_lu_factorizations"] == \
+        len(seen["factorised"])
+
+
 def test_on_step_sees_every_step_of_the_step_log(traced):
-    tr, rep, _calls, infos = traced
+    tr, rep, _seen, infos = traced
     assert all(isinstance(i, StepInfo) for i in infos)
     assert [i.master for i in infos] == \
         [sp.args["master"] for sp in tr.spans
